@@ -34,6 +34,9 @@ def main() -> None:
         roofline_report,
         serving_bench,
     )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = {
         "fig6_latency": paper_figures.fig6_latency,

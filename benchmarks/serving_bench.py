@@ -517,6 +517,12 @@ def mesh_rows():
     import subprocess
     import sys
 
+    if jax.default_backend() == "tpu":
+        # this process holds the chip: the child would be forced onto
+        # the CPU and its number would pass for a chip measurement
+        raise RuntimeError(
+            "the mesh rows run in a CPU child process; on a TPU host run "
+            "the serving bench with JAX_PLATFORMS=cpu")
     child = _MESH_CHILD % (ARCH, PAGED_PREFIX, GEN, MESH_TRIALS)
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env["PYTHONPATH"] = os.pathsep.join(

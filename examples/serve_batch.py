@@ -28,6 +28,7 @@ import numpy as np
 
 from repro import configs as cfglib
 from repro.core.modes import ExecutionMode, ExecutionPlan, LayerPlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.sampling import SamplingParams
 from repro.launch.scheduler import (
     ContinuousBatchingServer,
@@ -35,6 +36,12 @@ from repro.launch.scheduler import (
 )
 from repro.launch.serve import Server
 from repro.models.registry import get_model
+
+
+def device_desc() -> str:
+    """Where this run executes, as JAX reports it."""
+    dev = jax.devices()[0]
+    return f"{dev.platform} {dev.device_kind} x{len(jax.devices())}"
 
 
 def build_sampling(args) -> SamplingParams | None:
@@ -110,7 +117,7 @@ def build_plan(args, cfg):
 
 def run_static(args, cfg, api, params, plan):
     sample = build_sampling(args)
-    print(f"arch={cfg.arch_id} (reduced config for CPU), "
+    print(f"arch={cfg.arch_id} (smoke config, {device_desc()}), "
           f"batch={args.batch}, prompt={args.prompt_len}, gen={args.gen}, "
           f"plan={plan}, decode={args.decode}, sample={sample}")
     server = Server(cfg, params, max_len=args.prompt_len + args.gen,
@@ -139,7 +146,7 @@ def run_static(args, cfg, api, params, plan):
     dt = time.perf_counter() - t0
     total_new = args.batch * args.gen
     print(f"generated {total_new} tokens in {dt:.2f}s "
-          f"({total_new/dt:.1f} tok/s on CPU)")
+          f"({total_new/dt:.1f} tok/s on {device_desc()})")
     print("sample continuation ids:",
           result.tokens[0, args.prompt_len:args.prompt_len + 8].tolist())
 
@@ -206,7 +213,7 @@ def run_continuous(args, cfg, api, params, plan):
     done = sched.run()
     dt = time.perf_counter() - t0
     print(f"drained {len(done)} requests / {useful} tokens in {dt:.2f}s "
-          f"({useful/dt:.1f} tok/s on CPU, cold)")
+          f"({useful/dt:.1f} tok/s on {device_desc()}, cold)")
     # the executable-cache counters are THE re-trace regression signal:
     # repeat traffic of a shape/plan already served must be all hits, so
     # a compile count that grows run-over-run in the CI smoke log means
@@ -294,7 +301,7 @@ def run_rag(args, cfg, api, params, plan):
     done = sched.run()
     dt = time.perf_counter() - t0
     print(f"drained {len(done)} requests / {useful} tokens in {dt:.2f}s "
-          f"({useful/dt:.1f} tok/s on CPU, cold)")
+          f"({useful/dt:.1f} tok/s on {device_desc()}, cold)")
     print(sched.stats.summary())
     # the smoke's contract: every query retrieved and drained, the
     # shared corpus produced real chunk-level KV reuse (a zero here
@@ -410,8 +417,8 @@ def main():
                          "gather/scatter); 'slab' keeps the dense "
                          "round-trip reference segment")
     ap.add_argument("--use-pallas", action="store_true",
-                    help="enable the Pallas kernels (interpret mode off "
-                         "TPU) — CI's paged-attention kernel smoke")
+                    help="enable the Pallas kernels (interpret mode on the "
+                         "CPU) — CI's paged-attention kernel smoke")
     ap.add_argument("--block-size", type=int, default=8,
                     help="KV pool block size in token positions")
     ap.add_argument("--num-blocks", type=int, default=None,
@@ -479,6 +486,7 @@ def main():
                     help="sampling seed (same seed => same tokens)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = cfglib.get_smoke_config(args.arch)
     if args.use_pallas:
         cfg = dataclasses.replace(cfg, use_pallas=True)

@@ -47,6 +47,10 @@ ICI_LINKS_PER_CHIP: int = 3              # usable links on a torus slice
 ICI_BYTES_PER_S_PER_CHIP: float = ICI_BYTES_PER_S_PER_LINK * ICI_LINKS_PER_CHIP
 HBM_BYTES_PER_CHIP: int = 16 * 1024**3   # 16 GiB (v5e)
 VMEM_BYTES_PER_CHIP: int = 128 * 1024**2 # 128 MiB VMEM
+# What one pallas_call may hold in VMEM unless it asks for more: the
+# Mosaic compiler's default scoped-VMEM limit on v5e, which refuses a
+# kernel past it ("Scoped allocation ... exceeded scoped vmem limit").
+SCOPED_VMEM_BYTES: int = 16 * 1024**2
 
 # ----------------------------------------------------------------------------
 # Energy model constants.

@@ -7,7 +7,11 @@ Each op:
     math) — so models can call these unconditionally,
   * is jit-friendly (static flags only via closure/partial).
 
-``interpret`` is threaded through for CPU validation of the kernel bodies.
+``interpret`` is threaded through for CPU validation of the kernel bodies;
+the models pass ``interpret_kernels()``, true on the CPU platform only.
+The sidebar MLP and paged-attention ops record every dispatch, the jnp
+fallback included (``record_dispatches``), so a run on the chip can
+prove that its shapes took the kernels.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ from repro.kernels.paged_attention import (
 )
 from repro.kernels.sidebar_gated_mlp import sidebar_gated_mlp as _gated_kernel
 from repro.kernels.sidebar_matmul import sidebar_matmul as _matmul_kernel
+from repro.kernels.sidebar_mlp import choose_tiles as _choose_mlp_tiles
 from repro.kernels.sidebar_mlp import sidebar_mlp as _mlp_kernel
 from repro.kernels.sidebar_mlp import (
     sidebar_mlp_pipelined as _mlp_kernel_pipelined,
@@ -48,6 +53,14 @@ Array = jax.Array
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_kernels() -> bool:
+    """Whether the models run their Pallas kernels in interpret mode:
+    on the CPU platform only. On a TPU they compile for the chip, where
+    a kernel the compiler refuses fails the program instead of falling
+    back."""
+    return jax.default_backend() == "cpu"
 
 
 def _tileable(n: int, t: int = 128) -> bool:
@@ -223,12 +236,6 @@ def sidebar_mlp(
     """
     m, d = x.shape
     _, f = w1.shape
-    eligible = _tileable(m, 8) and _tileable(f) and _tileable(d)
-    use = (
-        use_kernel
-        if use_kernel is not None
-        else (eligible and (_on_tpu() or interpret))
-    )
     plan = current_plan()
     dma = (
         plan.mode is ExecutionMode.FLEXIBLE_DMA
@@ -242,6 +249,17 @@ def sidebar_mlp(
             depth = plan.depth  # the planner's scored choice, verbatim
         else:
             depth = 2 if pipelined else 1  # explicit opt-in: classic ring
+    eligible = (
+        _tileable(m, 8) and _tileable(f) and _tileable(d)
+        and _choose_mlp_tiles(m, d, f, x.dtype.itemsize, d2=w2.shape[1],
+                              sidebars=depth if pipelined else 1)
+        is not None
+    )
+    use = (
+        use_kernel
+        if use_kernel is not None
+        else (eligible and (_on_tpu() or interpret))
+    )
     if dma:
         _record("sidebar_mlp", plan.mode, 1, "dma", use)
         h = sidebar_matmul(x, w1, "identity", table=table,
@@ -278,7 +296,12 @@ def sidebar_gated_mlp(
     """y = (f(x@Wg) * (x@Wu)) @ Wd — fused gated sidebar kernel."""
     m, d = x.shape
     _, f = w_gate.shape
-    eligible = _tileable(m, 8) and _tileable(f) and _tileable(d)
+    eligible = (
+        _tileable(m, 8) and _tileable(f) and _tileable(d)
+        and _choose_mlp_tiles(m, d, f, x.dtype.itemsize,
+                              d2=w_down.shape[1], up_panels=2, sidebars=2)
+        is not None
+    )
     use = (
         use_kernel
         if use_kernel is not None

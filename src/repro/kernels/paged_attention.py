@@ -15,9 +15,9 @@ Two families:
 
   * **GQA decode** (one query token per row): q (B, H, Dh) against
     pooled k/v (P, Hkv, bs, Dh), grid (B, Hkv, nb) with the block axis
-    minor. int8-KV pools dequantize in-kernel from the pooled
-    per-(position, head) scales — the dequantized block never touches
-    HBM.
+    minor. int8-KV pools apply the pooled per-(position, head) scales
+    in-kernel, to the logits and the probabilities — no dequantized
+    block ever touches HBM.
   * **MLA absorbed decode**: q already projected into latent space
     (q_lat (B, H, kvr) fp32 + q_rope (B, H, rope)) against the pooled
     compressed cache (c_kv (P, bs, kvr), k_rope (P, bs, rope)), grid
@@ -167,6 +167,7 @@ def _gqa_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    hh = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -177,6 +178,15 @@ def _gqa_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
 
     length = lengths_ref[b]
 
+    def head_row(ref):
+        # the block holds every head's (Hkv, bs) scales (a (1, bs) block
+        # would break the TPU's last-two-dims tiling); pick this head's
+        # row as a (1, bs) lane vector with a masked sum
+        sc = ref[0]
+        rows = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+        return jnp.sum(jnp.where(rows == hh, sc, 0.0), axis=0,
+                       keepdims=True)
+
     # blocks at or past the row's frontier hold junk (or another row's
     # data): skip them entirely — the causal mask in block form
     @pl.when(j * block_size < length)
@@ -184,14 +194,16 @@ def _gqa_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
         q = q_ref[0, 0].astype(jnp.float32)       # (group, Dh)
         k = k_ref[0, 0]                           # (bs, Dh) — this row's
         v = v_ref[0, 0]                           # table[b, j] pool block
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, 0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0, 0][:, None]
         # static primitive #1 (MXU): q·K^T on the in-place pool block
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                 # (group, bs)
+        if quantized:
+            # int8 KV: the per-position scales factor out of the dot,
+            # so they multiply the logits' and the probabilities' key
+            # axis instead of dequantizing the block
+            s = s * head_row(ks_ref)
         kpos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, NEG_INF)
@@ -204,8 +216,12 @@ def _gqa_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
                                                   keepdims=True)
         m_ref[...] = m_curr
         # static primitive #2 (MXU): weighted value accumulation
-        pv = jnp.dot(p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
+        if quantized:
+            pv = jnp.dot(p * head_row(vs_ref), v.astype(jnp.float32),
+                         preferred_element_type=jnp.float32)
+        else:
+            pv = jnp.dot(p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + pv
 
     @pl.when(j == n_blocks - 1)
@@ -246,7 +262,7 @@ def paged_gqa_kernel(
         return (t[bb, jj], hh, 0, 0)
 
     def scale_map(bb, hh, jj, t, ln):
-        return (t[bb, jj], hh, 0)
+        return (t[bb, jj], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, group, dh), q_map),
@@ -255,7 +271,7 @@ def paged_gqa_kernel(
     ]
     args = [qg, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_map)] * 2
+        in_specs += [pl.BlockSpec((1, hkv, bs), scale_map)] * 2
         args += [k_scale, v_scale]
 
     kernel = functools.partial(

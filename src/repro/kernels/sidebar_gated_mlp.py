@@ -27,31 +27,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import constants
 from repro.core.function_table import DEFAULT_TABLE, FunctionTable
-from repro.kernels.sidebar_mlp import SUBLANE, LANE
+from repro.kernels.sidebar_mlp import plan_tiles
 
 Array = jax.Array
-
-
-def choose_tiles(m: int, d: int, f: int, itemsize: int = 2,
-                 vmem_budget: int = constants.VMEM_BYTES_PER_CHIP // 8) -> tuple[int, int]:
-    for bm in (256, 128, 64, 32, 16, 8):
-        if bm > m or m % bm:
-            continue
-        for bf in (1024, 512, 256, 128):
-            if bf > f or f % bf:
-                continue
-            ws = (
-                bm * d * itemsize          # x tile
-                + 3 * d * bf * itemsize    # wg, wu panels + wd panel
-                + bm * d * itemsize        # out tile
-                + 8 * bm * bf              # two fp32 sidebars
-                + 4 * bm * d               # accumulator
-            )
-            if ws <= vmem_budget:
-                return bm, bf
-    return SUBLANE, LANE
 
 
 def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, sb_g, sb_u, acc_ref, *,
@@ -92,8 +71,6 @@ def sidebar_gated_mlp(
     activation: str | Callable = "silu",
     *,
     table: FunctionTable = DEFAULT_TABLE,
-    block_m: int | None = None,
-    block_f: int | None = None,
     interpret: bool = False,
 ) -> Array:
     m, d = x.shape
@@ -106,10 +83,8 @@ def sidebar_gated_mlp(
     d2 = w_down.shape[1]
     fn = table.lookup(activation) if isinstance(activation, str) else activation
 
-    bm, bf = choose_tiles(m, d, f, x.dtype.itemsize)
-    bm, bf = block_m or bm, block_f or bf
-    if m % bm or f % bf:
-        raise ValueError(f"M={m}%{bm} or F={f}%{bf} != 0")
+    bm, bf = plan_tiles(m, d, f, d2, x.dtype.itemsize, up_panels=2,
+                        sidebars=2)
     n_f_blocks = f // bf
 
     kernel = functools.partial(

@@ -24,14 +24,16 @@ Tiling (BlockSpec):
   sidebar : VMEM (bm, bf) fp32         — the scratchpad
   acc     : VMEM (bm, D)  fp32         — output accumulator
 
-Per-step VMEM footprint (bm=128, bf=512, D=4096, bf16 in / fp32 scratch):
-x 1.0 MiB + w1 4.0 MiB + w2 4.0 MiB + out 1.0 MiB + sidebar 0.25 MiB +
-acc 2.0 MiB ≈ 12.3 MiB — comfortably inside a 16 MiB/core VMEM budget
-(``choose_tiles`` picks bm/bf to respect it for other D).
+VMEM (``vmem_bytes``): Pallas double-buffers every blocked operand (x,
+the weight panels, out), so each counts twice; the scratch counts once,
+and so does the f32 down-projection partial the compiler materialises
+next to the accumulator. ``choose_tiles`` picks the largest (bm, bf)
+whose total fits the compiler's default scoped-VMEM limit
+(``constants.SCOPED_VMEM_BYTES``): at D = 6144 that is (8, 256) for
+decode rows and (64, 128) for prefill rows.
 
-The contraction dimension D stays resident (no k-blocking): for the
-assigned architectures D = d_model ≤ 16 384, and the dominant tile is the
-W panels; ``choose_tiles`` shrinks bf accordingly.
+The contraction dimension D stays resident (no k-blocking), so the
+row tile and the weight panels shrink as D grows.
 """
 
 from __future__ import annotations
@@ -49,38 +51,52 @@ from repro.core.function_table import DEFAULT_TABLE, FunctionTable
 
 Array = jax.Array
 
-LANE = 128  # TPU lane width; last dims should be multiples of this
-SUBLANE = 8
 
+def vmem_bytes(bm: int, bf: int, d: int, d2: int, itemsize: int, *,
+               up_panels: int = 1, sidebars: int = 1) -> int:
+    """VMEM one fused-MLP ``pallas_call`` asks the compiler for.
 
-def choose_tiles(m: int, d: int, f: int, itemsize: int = 2,
-                 vmem_budget: int = constants.VMEM_BYTES_PER_CHIP // 8,
-                 sidebar_copies: int = 1) -> tuple[int, int]:
-    """Pick (bm, bf) so the per-step working set fits the VMEM budget.
-
-    working_set(bm, bf) = bm*d*itemsize   (x tile)
-                        + d*bf*itemsize   (w1 panel)
-                        + bf*d*itemsize   (w2 panel)
-                        + bm*d*itemsize   (out tile)
-                        + 4*bm*bf*copies  (sidebar; 2 copies when ping-pong)
-                        + 4*bm*d          (accumulator, fp32)
+    Blocked operands are double-buffered: the x row tile, ``up_panels``
+    (D, bf) up-projection panels (2 for the gated kernel), the (bf, D2)
+    down panel and the out tile. Scratch is single: ``sidebars`` fp32
+    (bm, bf) tiles (the ring depth, or the gated kernel's two paths)
+    and the fp32 accumulator, plus the fp32 partial of the second
+    matmul, which the compiler keeps beside it.
     """
+    blocked = bm * d + up_panels * d * bf + bf * d2 + bm * d2
+    return 2 * itemsize * blocked + 4 * bm * bf * sidebars + 8 * bm * d2
+
+
+def choose_tiles(m: int, d: int, f: int, itemsize: int = 2, *,
+                 d2: int | None = None, up_panels: int = 1,
+                 sidebars: int = 1) -> tuple[int, int] | None:
+    """The largest (bm, bf) dividing (m, f) whose ``vmem_bytes`` fits
+    ``constants.SCOPED_VMEM_BYTES``; None when no tiling fits (the op
+    then takes its jnp reference, and records it)."""
+    d2 = d if d2 is None else d2
     for bm in (256, 128, 64, 32, 16, 8):
         if bm > m or m % bm:
             continue
         for bf in (1024, 512, 256, 128):
             if bf > f or f % bf:
                 continue
-            ws = (
-                bm * d * itemsize
-                + 2 * d * bf * itemsize
-                + bm * d * itemsize
-                + 4 * bm * bf * sidebar_copies
-                + 4 * bm * d
-            )
-            if ws <= vmem_budget:
+            need = vmem_bytes(bm, bf, d, d2, itemsize, up_panels=up_panels,
+                              sidebars=sidebars)
+            if need <= constants.SCOPED_VMEM_BYTES:
                 return bm, bf
-    return SUBLANE, LANE
+    return None
+
+
+def plan_tiles(m: int, d: int, f: int, d2: int, itemsize: int,
+               **kw) -> tuple[int, int]:
+    """``choose_tiles`` for a kernel about to launch: raises when no
+    tiling fits."""
+    tiles = choose_tiles(m, d, f, itemsize, d2=d2, **kw)
+    if tiles is None:
+        raise ValueError(
+            f"no (bm, bf) tiling of M={m} F={f} at D={d} fits "
+            f"{constants.SCOPED_VMEM_BYTES} bytes of scoped VMEM")
+    return tiles
 
 
 def _kernel(x_ref, w1_ref, w2_ref, o_ref, sidebar_ref, acc_ref, *,
@@ -175,8 +191,6 @@ def sidebar_mlp(
     activation: str | Callable = "relu",
     *,
     table: FunctionTable = DEFAULT_TABLE,
-    block_m: int | None = None,
-    block_f: int | None = None,
     interpret: bool = False,
 ) -> Array:
     """Fused f(x @ w1) @ w2 with the intermediate resident in VMEM.
@@ -185,7 +199,6 @@ def sidebar_mlp(
       x: (M, D) activations.
       w1: (D, F) up-projection.  w2: (F, D2) down-projection.
       activation: function-table key (or raw callable) — the flexible op.
-      block_m/block_f: tile overrides; default via ``choose_tiles``.
       interpret: run the kernel body in Python on CPU (validation mode).
     """
     m, d = x.shape
@@ -195,11 +208,7 @@ def sidebar_mlp(
         raise ValueError(f"shape mismatch: x{x.shape} w1{w1.shape} w2{w2.shape}")
     fn = table.lookup(activation) if isinstance(activation, str) else activation
 
-    bm, bf = choose_tiles(m, d, f, x.dtype.itemsize)
-    bm = block_m or bm
-    bf = block_f or bf
-    if m % bm or f % bf:
-        raise ValueError(f"M={m} % bm={bm} or F={f} % bf={bf} != 0")
+    bm, bf = plan_tiles(m, d, f, d2, x.dtype.itemsize)
     n_f_blocks = f // bf
 
     grid = (m // bm, n_f_blocks)
@@ -231,8 +240,6 @@ def sidebar_mlp_pipelined(
     activation: str | Callable = "relu",
     *,
     table: FunctionTable = DEFAULT_TABLE,
-    block_m: int | None = None,
-    block_f: int | None = None,
     depth: int = 2,
     interpret: bool = False,
 ) -> Array:
@@ -254,11 +261,7 @@ def sidebar_mlp_pipelined(
         raise ValueError(f"ring depth must be >= 1, got {depth}")
     fn = table.lookup(activation) if isinstance(activation, str) else activation
 
-    bm, bf = choose_tiles(m, d, f, x.dtype.itemsize, sidebar_copies=depth)
-    bm = block_m or bm
-    bf = block_f or bf
-    if m % bm or f % bf:
-        raise ValueError(f"M={m} % bm={bm} or F={f} % bf={bf} != 0")
+    bm, bf = plan_tiles(m, d, f, d2, x.dtype.itemsize, sidebars=depth)
     n_f_blocks = f // bf
     lag = depth - 1
 

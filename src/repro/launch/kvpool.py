@@ -61,14 +61,9 @@ import numpy as np
 
 from repro.kernels import ops as kops
 from repro.models import layers as L
+from repro.models.attention import SCRATCH_BLOCK
 
 Array = jax.Array
-
-# Block 0 is the reserved scratch block: free slots' block tables and the
-# padded tail of every table point at it, so clamped/dead writes land in
-# junk that no unmasked read ever sees (the same stale-KV-behind-the-
-# causal-mask argument the slot scheduler already relies on).
-SCRATCH_BLOCK = 0
 
 
 class KVPoolError(RuntimeError):
@@ -426,17 +421,19 @@ class KVPool:
     """
 
     def __init__(self, api, cfg, minfo, *, num_blocks: int,
-                 block_size: int, place=None) -> None:
+                 block_size: int, shardings=None) -> None:
         self.cfg = cfg
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.batch_axes = probe_batch_axes(api, cfg, minfo, block_size)
         self.length_axes = probe_length_axes(api, cfg, minfo, num_blocks)
-        self.cache = api.init_cache(cfg, minfo, num_blocks, block_size)
-        if place is not None:
-            # tensor-parallel serving: the pool's KV-head axes live on
-            # the mesh's "model" axis, block/position axes replicate
-            self.cache = place(self.cache)
+        # made on the device in one program; under tensor-parallel
+        # serving ``shardings`` puts the KV-head axes on the mesh's
+        # "model" axis from the start, so no chip ever holds the whole
+        # pool
+        self.cache = jax.jit(
+            lambda: api.init_cache(cfg, minfo, num_blocks, block_size),
+            out_shardings=shardings)()
 
     def copy_blocks(self, dst: list[int], src: list[int]) -> None:
         """Device copy pool[src] -> pool[dst] on every leaf (the
@@ -612,14 +609,14 @@ class PagedKVManager:
     """
 
     def __init__(self, api, cfg, minfo, *, num_blocks: int,
-                 block_size: int, place=None,
+                 block_size: int, shardings=None,
                  spare_blocks: int = 0) -> None:
         self.block_size = int(block_size)
         self.spare_blocks = int(spare_blocks)
         self.alloc = BlockAllocator(num_blocks)
         self.pool = KVPool(api, cfg, minfo,
                            num_blocks=num_blocks + self.spare_blocks,
-                           block_size=block_size, place=place)
+                           block_size=block_size, shardings=shardings)
 
     @property
     def spare_ids(self) -> range:

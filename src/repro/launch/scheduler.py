@@ -1208,7 +1208,8 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
         self.mgr = kvp.PagedKVManager(
             self.api, self.cfg, self.minfo,
             num_blocks=nb, block_size=self.block_size,
-            place=self.tp.place_cache if self.tp is not None else None,
+            shardings=(self.tp.cache_shardings() if self.tp is not None
+                       else None),
             spare_blocks=self.num_slots * self._n_scratch,
         )
         if self._spec_on:
@@ -1925,7 +1926,10 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
         # entry must be a real pool block (gathers promise in-bounds)
         # and every active row's write frontier must stay inside its
         # allocated span (writes past it would silently drop)
-        bt_np = self._tables[:, :width]
+        # a copy: the host-to-device transfer of a numpy array may read
+        # it after the dispatch returns, and ``self._tables`` is
+        # rewritten in place at the next boundary
+        bt_np = self._tables[:, :width].copy()
         kvp.validate_tables(bt_np, self.mgr.pool.num_blocks)
         for i in active:
             rb = self._slot_rb[i]

@@ -50,7 +50,6 @@ from repro.launch.sampling import SamplingParams
 from repro.models import layers as L
 from repro.models.registry import ModelApi, get_model
 from repro.parallel import tp as tplib
-from repro.parallel.compat import shard_map as _shard_map
 
 Array = jax.Array
 
@@ -221,7 +220,7 @@ def make_serve_step(cfg: ModelConfig, api: ModelApi, minfo: L.MeshInfo, mesh,
 
         def tp_serve_step(params, tokens, cache, pos, memory=None,
                           sample=None, block_tables=None):
-            fn = _shard_map(
+            fn = jax.shard_map(
                 tp_body, mesh=tp.mesh,
                 in_specs=(tp.param_pspecs, rep, tp.cache_pspecs, rep, rep,
                           rep, rep),
@@ -287,7 +286,7 @@ def make_prefill_step(cfg: ModelConfig, api: ModelApi, minfo: L.MeshInfo,
 
         def tp_prefill_step(params, batch, cache, sample=None,
                             cache_pos=None, block_tables=None):
-            fn = _shard_map(
+            fn = jax.shard_map(
                 tp_body, mesh=tp.mesh,
                 in_specs=(tp.param_pspecs, rep, tp.cache_pspecs, rep, rep,
                           rep),
@@ -361,7 +360,7 @@ def make_verify_step(cfg: ModelConfig, api: ModelApi, minfo: L.MeshInfo,
 
         def tp_verify_step(params, tokens, cache, pos, block_tables,
                            sample=None):
-            fn = _shard_map(
+            fn = jax.shard_map(
                 tp_body, mesh=tp.mesh,
                 in_specs=(tp.param_pspecs, rep, tp.cache_pspecs, rep, rep,
                           rep),

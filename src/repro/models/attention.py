@@ -138,6 +138,13 @@ def rowwise_pos(pos) -> bool:
 # the dense layout the attention math runs on.
 # ---------------------------------------------------------------------------
 
+# Block 0 is the reserved scratch block: free slots' block tables and the
+# padded tail of every table point at it, so clamped/dead writes land in
+# junk that no unmasked read ever sees (the same stale-KV-behind-the-
+# causal-mask argument the slot scheduler already relies on).
+SCRATCH_BLOCK = 0
+
+
 def _paged_write_index(block_tables: Array, cache_pos, s: int, bs: int,
                        num_blocks: int):
     """Physical (block, offset) for each written token position.
@@ -237,7 +244,7 @@ def _attend(q: Array, k: Array, v: Array, *, causal: bool, cfg: ModelConfig,
     static_end = isinstance(offset, int) and offset == t - s
     if cfg.use_pallas and s % 128 == 0 and t % 128 == 0 and static_end:
         return kops.flash_attention(q, k, v, causal=causal,
-                                    interpret=jax.default_backend() != "tpu")
+                                    interpret=kops.interpret_kernels())
     group = h // k.shape[1]
     scale = 1.0 / math.sqrt(dh)
     if s <= CHUNK_Q or s % CHUNK_Q:
@@ -372,27 +379,40 @@ def gqa_attention(
             # cache are never in any row's write range — the scheduler's
             # copy-on-write guarantee).
             bs_blk = cache["k"].shape[2]
+            num_blocks = cache["k"].shape[0]
             pb, po = _paged_write_index(block_tables, cache_pos, s, bs_blk,
-                                        cache["k"].shape[0])
-            if s == 1:
-                kv_vals = (kq[:, :, 0, :], vq[:, :, 0, :])
-            else:
-                kv_vals = (kq.transpose(0, 2, 1, 3), vq.transpose(0, 2, 1, 3))
-            # mode="drop": out-of-table positions carry the OOB sentinel
-            # block id and must vanish, never clamp onto a real block
-            new_cache["k"] = cache["k"].at[pb, :, po, :].set(
-                kv_vals[0], mode="drop")
-            new_cache["v"] = cache["v"].at[pb, :, po, :].set(
-                kv_vals[1], mode="drop")
+                                        num_blocks)
+            vals = {"k": kq, "v": vq}
             if int8:
-                s_vals = ((ks[:, :, 0], vs[:, :, 0]) if s == 1
-                          else (ks.transpose(0, 2, 1), vs.transpose(0, 2, 1)))
-                new_cache["k_scale"] = (
-                    cache["k_scale"].at[pb, :, po].set(
-                        s_vals[0], mode="drop"))
-                new_cache["v_scale"] = (
-                    cache["v_scale"].at[pb, :, po].set(
-                        s_vals[1], mode="drop"))
+                vals.update(k_scale=ks, v_scale=vs)
+            if s == 1:
+                # decode: one in-place dynamic_update_slice per row. A
+                # (block, offset)-indexed scatter gets its own layout
+                # from the TPU compiler, which then copies the whole
+                # pool into it and each layer's slice back out for the
+                # paged kernel, every step. A slice write keeps the
+                # kernel's row-major layout. An out-of-table position
+                # (the drop sentinel) writes the scratch block instead,
+                # whose content no unmasked read ever sees.
+                pb = jnp.where(pb < num_blocks, pb, SCRATCH_BLOCK)
+                for name, val in vals.items():
+                    leaf = cache[name]
+                    for r in range(b):
+                        row = val[r, :, 0]                 # (Hkv[, Dh])
+                        row = row.reshape(1, row.shape[0], 1,
+                                          *row.shape[1:])
+                        start = (pb[r], 0, po[r]) + (0,) * (leaf.ndim - 3)
+                        leaf = jax.lax.dynamic_update_slice(
+                            leaf, row.astype(leaf.dtype), start)
+                    new_cache[name] = leaf
+            else:
+                # mode="drop": out-of-table positions carry the OOB
+                # sentinel block id and must vanish, never clamp onto a
+                # real block
+                for name, val in vals.items():
+                    val = jnp.swapaxes(val, 1, 2)          # (B, s, Hkv...)
+                    new_cache[name] = cache[name].at[pb, :, po].set(
+                        val, mode="drop")
         elif rowwise_pos(cache_pos):
             # per-row scatter: slot row i writes its own position — ONE
             # batched program over unaligned slots instead of num_slots
@@ -449,8 +469,7 @@ def gqa_attention(
                 k_scale=new_cache["k_scale"] if int8 else None,
                 v_scale=new_cache["v_scale"] if int8 else None,
                 compute_dtype=cfg.dtype,
-                interpret=cfg.use_pallas
-                and jax.default_backend() != "tpu",
+                interpret=cfg.use_pallas and kops.interpret_kernels(),
             )
             out = ctx.reshape(b, 1, h * dh)
             return tp.psum_partial(linear(out, params["wo"])), new_cache
@@ -590,8 +609,7 @@ def mla_attention(
                 q_lat[:, 0], q_rope[:, 0], new_cache["c_kv"],
                 new_cache["k_rope"], block_tables, lengths, scale=scale,
                 compute_dtype=cfg.dtype,
-                interpret=cfg.use_pallas
-                and jax.default_backend() != "tpu",
+                interpret=cfg.use_pallas and kops.interpret_kernels(),
             )[:, None]                                       # (B,1,H,kvr)
         else:
             t = c_kv_full.shape[1]

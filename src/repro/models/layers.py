@@ -93,26 +93,38 @@ def is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
-def materialize(key: Array, tree, scale_override: float | None = None):
-    """Initialize every ParamSpec leaf with its declared initializer."""
+def materialize(key: Array, tree, scale_override: float | None = None,
+                shardings=None):
+    """Initialize every ParamSpec leaf with its declared initializer.
+
+    One jitted program makes every leaf on the device, in its own dtype
+    and (with ``shardings``, a matching tree) already partitioned, so
+    no full-size float32 or unsharded copy of a leaf ever exists
+    outside it.
+    """
     leaves, treedef = jax.tree.flatten(tree, is_leaf=is_spec)
-    keys = jax.random.split(key, len(leaves))
-    out = []
-    for k, spec in zip(keys, leaves):
-        # second-to-last dim is the contraction (fan-in) dim; leading dims
-        # are layer-stack / expert dims and must not affect the scale.
-        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else max(spec.shape[-1], 1)
-        if spec.init == "zeros":
-            arr = jnp.zeros(spec.shape, spec.dtype)
-        elif spec.init == "ones":
-            arr = jnp.ones(spec.shape, spec.dtype)
-        elif spec.init == "embed":
-            arr = (jax.random.normal(k, spec.shape, jnp.float32) * 0.02).astype(spec.dtype)
-        else:
-            std = scale_override or (1.0 / math.sqrt(fan_in))
-            arr = (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(spec.dtype)
-        out.append(arr)
-    return jax.tree.unflatten(treedef, out)
+
+    def make(key):
+        keys = jax.random.split(key, len(leaves))
+        return jax.tree.unflatten(treedef, [
+            _init_leaf(k, spec, scale_override)
+            for k, spec in zip(keys, leaves)])
+
+    return jax.jit(make, out_shardings=shardings)(key)
+
+
+def _init_leaf(k: Array, spec: ParamSpec, scale_override: float | None):
+    # second-to-last dim is the contraction (fan-in) dim; leading dims
+    # are layer-stack / expert dims and must not affect the scale.
+    fan_in = spec.shape[-2] if len(spec.shape) >= 2 else max(spec.shape[-1], 1)
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, spec.dtype)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, spec.dtype)
+    if spec.init == "embed":
+        return (jax.random.normal(k, spec.shape, jnp.float32) * 0.02).astype(spec.dtype)
+    std = scale_override or (1.0 / math.sqrt(fan_in))
+    return (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(spec.dtype)
 
 
 def abstract(tree):

@@ -11,8 +11,6 @@ mode with ``cfg.use_pallas`` the plain MLP runs through the fused
 
 from __future__ import annotations
 
-import math
-
 import jax
 
 from repro.configs.base import ModelConfig
@@ -61,25 +59,25 @@ def mlp(
     act_name = activation or cfg.activation
     act = table.lookup(act_name)
     d = x.shape[-1]
-    rows = math.prod(x.shape[:-1]) if x.ndim > 1 else 0
-    kernel_ok = cfg.use_pallas and x.ndim >= 2 and rows % 8 == 0
-    if cfg.gated_mlp:
-        if kernel_ok:
+    if cfg.use_pallas and x.ndim >= 2:
+        # the ops decide kernel vs jnp fallback from the shapes, and
+        # record the choice either way (kops.record_dispatches)
+        x2 = x.reshape(-1, d)
+        if cfg.gated_mlp:
             y = kops.sidebar_gated_mlp(
-                x.reshape(rows, d), params["w_gate"], params["w_up"],
-                params["w_down"], act_name, table=table,
-                interpret=jax.default_backend() != "tpu",
+                x2, params["w_gate"], params["w_up"], params["w_down"],
+                act_name, table=table, interpret=kops.interpret_kernels(),
             )
-            return tp.psum_partial(y.reshape(x.shape))
+        else:
+            y = kops.sidebar_mlp(
+                x2, params["w_up"], params["w_down"], act_name,
+                table=table, interpret=kops.interpret_kernels(),
+            )
+        return tp.psum_partial(y.reshape(x.shape))
+    if cfg.gated_mlp:
         g = act(linear(x, params["w_gate"]))          # flexible (VPU)
         u = linear(x, params["w_up"])                 # static  (MXU)
         return tp.psum_partial(
             linear((g * u).astype(x.dtype), params["w_down"]))
-    if kernel_ok:
-        y = kops.sidebar_mlp(
-            x.reshape(rows, d), params["w_up"], params["w_down"], act_name,
-            table=table, interpret=jax.default_backend() != "tpu",
-        )
-        return tp.psum_partial(y.reshape(x.shape))
     h = act(linear(x, params["w_up"]))
     return tp.psum_partial(linear(h.astype(x.dtype), params["w_down"]))

@@ -161,9 +161,7 @@ def moe(
             )
             return jax.lax.psum(y_l, "model")
 
-        from repro.parallel.compat import shard_map
-
-        y = shard_map(
+        y = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(tok_spec, r_spec, ew_spec, ew_spec, ed_spec),

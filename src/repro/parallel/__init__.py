@@ -1,5 +1,5 @@
-"""Parallelism substrate: sharding hints + jax mesh-API compat."""
+"""Parallelism substrate: sharding hints, tensor-parallel context, meshes."""
 
-from repro.parallel.compat import AxisType, auto_mesh, make_mesh, shard_map
+from repro.parallel.compat import auto_mesh
 
-__all__ = ["AxisType", "auto_mesh", "make_mesh", "shard_map"]
+__all__ = ["auto_mesh"]

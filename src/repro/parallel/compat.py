@@ -1,85 +1,21 @@
-"""jax version compatibility for mesh construction.
+"""Mesh construction.
 
-``jax.sharding.AxisType`` and the ``axis_types=`` kwarg of
-``jax.make_mesh`` first appeared after jax 0.4.37; this environment pins
-0.4.37. Everything in the repo that builds a mesh goes through
-``make_mesh`` below, which:
-
-  * accepts ``axis_types`` and forwards it when the installed jax
-    supports it,
-  * silently drops it otherwise (pre-explicit-axis-type jax treats every
-    mesh axis as "auto", which is exactly what all call sites request),
-  * exposes an ``AxisType`` alias (the real enum when present, a small
-    stand-in enum otherwise) so call sites can still spell
-    ``AxisType.Auto`` uniformly.
-
-Keep this the ONLY place that feature-detects the mesh API.
+``jax.make_mesh`` makes every axis Explicit by default; the repo's
+sharded programs rely on GSPMD propagation (Auto axes) outside their
+``jax.shard_map`` regions. ``auto_mesh`` is the one place a mesh is
+built.
 """
 
 from __future__ import annotations
 
-import enum
-import inspect
 from typing import Sequence
 
 import jax
-
-
-class _AxisTypeFallback(enum.Enum):
-    """Stand-in for jax.sharding.AxisType on jax versions without it."""
-
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = getattr(jax.sharding, "AxisType", _AxisTypeFallback)
-
-_MAKE_MESH_HAS_AXIS_TYPES = "axis_types" in inspect.signature(
-    jax.make_mesh
-).parameters
-
-
-def make_mesh(
-    axis_shapes: Sequence[int],
-    axis_names: Sequence[str],
-    *,
-    axis_types: Sequence | None = None,
-    devices=None,
-):
-    """``jax.make_mesh`` that tolerates jax versions without axis_types."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across jax versions.
-
-    jax < 0.5 exposes it as ``jax.experimental.shard_map.shard_map`` and
-    spells the replication-check kwarg ``check_rep``; newer jax promotes
-    it to ``jax.shard_map`` with ``check_vma``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
+from jax.sharding import AxisType
 
 
 def auto_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
     """Mesh with every axis in 'auto' sharding mode (the repo default)."""
-    return make_mesh(
-        axis_shapes,
-        axis_names,
-        axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
-    )
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names))

@@ -10,8 +10,9 @@ tree as it actually is:
            (src/repro/..., tests/..., benchmarks/..., examples/...,
            tools/..., docs/...) must exist;
   flags  — every ``--flag`` a doc names must be a real flag of
-           examples/serve_batch.py (parsed from its add_argument
-           calls) or one of the few known non-argparse flags.
+           examples/serve_batch.py or chip_smoke.py (parsed from their
+           add_argument calls) or one of the few known non-argparse
+           flags.
 
 Run: python tools/check_docs.py          (from the repo root or not —
 the repo root is located relative to this file). Exit 0 = docs clean;
@@ -48,8 +49,11 @@ KNOWN_FLAGS = {
 
 
 def serve_flags() -> set[str]:
-    src = (REPO / "examples" / "serve_batch.py").read_text()
-    return set(ARG_RE.findall(src)) | KNOWN_FLAGS
+    flags = set(KNOWN_FLAGS)
+    for script in (REPO / "examples" / "serve_batch.py",
+                   REPO / "chip_smoke.py"):
+        flags |= set(ARG_RE.findall(script.read_text()))
+    return flags
 
 
 def check_file(path: pathlib.Path, flags: set[str]) -> list[str]:
@@ -73,8 +77,8 @@ def check_file(path: pathlib.Path, flags: set[str]) -> list[str]:
     for flag in sorted(set(FLAG_RE.findall(text))):
         if flag not in flags:
             errors.append(
-                f"{rel}: names flag {flag}, which examples/"
-                f"serve_batch.py does not define"
+                f"{rel}: names flag {flag}, which neither examples/"
+                f"serve_batch.py nor chip_smoke.py defines"
             )
     return errors
 
