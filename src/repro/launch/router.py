@@ -98,20 +98,13 @@ class FleetStats:
 
 
 def sum_stats(per_replica: list[SchedulerStats]) -> SchedulerStats:
-    """Element-wise sum of the counter fields (every scalar field of
+    """Element-wise sum of the counter fields (every field of
     ``SchedulerStats`` is an additive count; the rates are properties
-    derived from the summed counts, so they aggregate correctly). The
-    per-priority latency-sample dicts concatenate instead — fleet tail
-    percentiles must come from the pooled samples, not a sum."""
+    derived from the summed counts, so they aggregate correctly)."""
     out = SchedulerStats()
     for st in per_replica:
         for f in dataclasses.fields(SchedulerStats):
-            mine, theirs = getattr(out, f.name), getattr(st, f.name)
-            if isinstance(mine, dict):
-                for k, v in theirs.items():
-                    mine.setdefault(k, []).extend(v)
-            else:
-                setattr(out, f.name, mine + theirs)
+            setattr(out, f.name, getattr(out, f.name) + getattr(st, f.name))
     return out
 
 

@@ -100,6 +100,7 @@ from repro.launch.serve import (
     make_verify_step,
 )
 from repro.launch.spec import SpecConfig, accepted_prefix, make_draft_program
+from repro.launch.tracing import Tracer
 from repro.models import layers as L
 from repro.models.registry import get_model
 
@@ -114,7 +115,13 @@ DEFAULT_BUCKETS = (16, 32, 64, 128)
 
 @dataclasses.dataclass(frozen=True)
 class FinishedRequest:
-    """One drained request: the prompt plus every generated token."""
+    """One drained request: the prompt plus every generated token.
+
+    ``ttft`` and ``itl`` are dispatch times on the scheduler's clock:
+    from submit to the dispatch of the program that produced the first
+    token, and the mean gap between dispatches after it. On an
+    asynchronous device a dispatch is the enqueue, not the moment the
+    token reaches the host."""
 
     rid: int
     prompt: np.ndarray        # (S,) int32 — as submitted
@@ -122,7 +129,7 @@ class FinishedRequest:
     prompt_len: int
     generated: int
     ttft: float = float("nan")   # submit -> first-token dispatch (s)
-    itl: float = float("nan")    # mean inter-token latency (s)
+    itl: float = float("nan")    # mean gap between dispatches (s)
 
 
 @dataclasses.dataclass(eq=False)
@@ -193,6 +200,12 @@ class SchedulerStats:
     ``spilled_blocks``...) and the speculative-decoding group
     (``spec_steps``/``spec_drafted``/``spec_accepted``/
     ``spec_commit_copies``, with ``spec_acceptance_rate`` derived).
+    ``compile_calls`` counts program calls that traced and compiled (or
+    loaded) a program ``_compiled`` had just built, the calls under a
+    ``compile`` span; ``retire_waits`` counts ``_materialize`` calls
+    that found a finished request's token buffer not yet computed
+    (``is_ready()`` false), so the host copy waited for the device: a
+    drained pipeline.
     Under speculation ``decode_steps`` still counts EMITTED tokens
     (1..k+1 per row per verify) and ``wasted_steps`` absorbs the
     rejected remainder, so throughput accounting stays comparable with
@@ -202,12 +215,14 @@ class SchedulerStats:
     # executable cache
     compiles: int = 0
     hits: int = 0
+    compile_calls: int = 0
     # admission / decode
     admitted: int = 0
     segments: int = 0
     decode_steps: int = 0
     wasted_steps: int = 0
     admit_deferrals: int = 0
+    retire_waits: int = 0
     # paged pool (PagedContinuousBatchingServer only)
     stage_chunks: int = 0
     stage_stalls: int = 0
@@ -238,39 +253,12 @@ class SchedulerStats:
     retrieval_overlapped: int = 0   # of those, hidden behind a dispatch
     retrieval_chunk_blocks: int = 0  # retrieved-chunk blocks staged
     retrieval_chunk_hits: int = 0    # of those, spliced from the index
-    # per-priority-class latency samples (seconds); dict fields merge by
-    # concatenation in ``router.sum_stats``
-    ttft_s: dict = dataclasses.field(default_factory=dict)
-    itl_s: dict = dataclasses.field(default_factory=dict)
 
     def __getitem__(self, key: str) -> int:
         return getattr(self, key)
 
     def __setitem__(self, key: str, value: int) -> None:
         setattr(self, key, value)
-
-    # -- per-class latency --------------------------------------------------
-    def record_ttft(self, priority: int, seconds: float) -> None:
-        self.ttft_s.setdefault(priority, []).append(float(seconds))
-
-    def record_itl(self, priority: int, seconds: float) -> None:
-        self.itl_s.setdefault(priority, []).append(float(seconds))
-
-    @staticmethod
-    def _tail(samples: dict, q: float, priority: int | None) -> float:
-        xs = (samples.get(priority, []) if priority is not None
-              else [x for v in samples.values() for x in v])
-        return float(np.percentile(xs, q)) if xs else float("nan")
-
-    def ttft_tail(self, q: float = 95.0,
-                  priority: int | None = None) -> float:
-        """Per-class (or overall) TTFT tail quantile in seconds — the
-        SLO gate the overload bench reports per priority class."""
-        return self._tail(self.ttft_s, q, priority)
-
-    def itl_tail(self, q: float = 95.0,
-                 priority: int | None = None) -> float:
-        return self._tail(self.itl_s, q, priority)
 
     @property
     def exec_hit_rate(self) -> float:
@@ -285,13 +273,6 @@ class SchedulerStats:
         splicing the walk never stops early, so lookups ≈ walked and
         the two now differ only in old recorded data)."""
         return self.prefix_block_hits / max(self.prefix_prompt_blocks, 1)
-
-    @property
-    def prefix_lookup_hit_rate(self) -> float:
-        """Deprecated: hits over index LOOKUPS, the pre-chunk-addressing
-        definition of ``prefix_hit_rate``. Kept one release for
-        dashboards pinned to the old denominator."""
-        return self.prefix_block_hits / max(self.prefix_block_lookups, 1)
 
     @property
     def retrieval_chunk_hit_rate(self) -> float:
@@ -447,6 +428,9 @@ class ContinuousBatchingServer:
         self._done_raw: list[tuple] = []   # retired, not yet materialized
         self._deferred = False             # admission hysteresis armed
         self.stats = SchedulerStats()
+        # host spans while a profiler session collects (launch.tracing)
+        self.tracer = Tracer()
+        self._uncalled: set = set()        # built by _compiled, not yet run
         # SLO scheduling: "edf" admits/stages by (priority, deadline,
         # arrival); "fifo" is the strict-arrival baseline the overload
         # bench compares against (preemption still guards lazy growth)
@@ -484,10 +468,22 @@ class ContinuousBatchingServer:
         fn = self._exec.get(key)
         if fn is None:
             fn = self._exec[key] = builder()
+            self._uncalled.add(fn)
             self.stats.compiles += 1
         else:
             self.stats.hits += 1
         return fn
+
+    def _launch(self, fn: Callable):
+        """The span around a call of ``fn``: ``compile`` on the first
+        call of a program ``_compiled`` built (it traces, lowers and
+        compiles the program, or loads it from the persistent cache),
+        ``dispatch`` on every later one."""
+        if fn in self._uncalled:
+            self._uncalled.discard(fn)
+            self.stats.compile_calls += 1
+            return self.tracer.span("compile")
+        return self.tracer.span("dispatch")
 
     def executable_cache_keys(self) -> list[tuple]:
         return sorted(self._exec, key=repr)
@@ -512,9 +508,9 @@ class ContinuousBatchingServer:
         requests for staging/admission (higher first under
         ``scheduling="edf"``; ignored by FIFO), and ``ttft_target`` /
         ``itl_target`` (seconds) attach SLO targets: the TTFT target
-        sets the EDF deadline (``submit time + target``), both are
-        reported per-request (``r.ttft`` / ``r.itl``) and as per-class
-        distributions in ``stats``. No-target requests are best-effort
+        sets the EDF deadline (``submit time + target``); each finished
+        request reports its own ``r.ttft`` / ``r.itl`` (dispatch
+        times). No-target requests are best-effort
         — they sort behind every deadline but are never starved of a
         free slot."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -649,11 +645,12 @@ class ContinuousBatchingServer:
             lambda: self._admit_fn(with_prefill=bool(bucket)),
         )
         toks = np.asarray([[r.prompt[-1]] for r in reqs], np.int32)
-        nxt, self._toks, self.cache = admit(
-            self.params, padded, self.cache, self._toks, jnp.asarray(toks),
-            jnp.asarray(s_true - 1), jnp.asarray(slot_idxs, jnp.int32),
-            state,
-        )
+        with self._launch(admit):
+            nxt, self._toks, self.cache = admit(
+                self.params, padded, self.cache, self._toks,
+                jnp.asarray(toks), jnp.asarray(s_true - 1),
+                jnp.asarray(slot_idxs, jnp.int32), state,
+            )
         now = self._clock()
         for j, slot_idx in enumerate(slot_idxs):
             r = reqs[j]
@@ -668,7 +665,6 @@ class ContinuousBatchingServer:
             slot.key = keys[j]
             slot.req = r
             slot.first_t = now     # first token dispatched here
-            self.stats.record_ttft(r.priority, now - r.submit_t)
             self.stats.admitted += 1
             if slot.remaining == 0:
                 self._retire(slot_idx)
@@ -685,7 +681,6 @@ class ContinuousBatchingServer:
             ttft = slot.first_t - slot.req.submit_t
             if slot.generated > 1:
                 itl = (self._clock() - slot.first_t) / (slot.generated - 1)
-                self.stats.record_itl(slot.req.priority, itl)
         self._done_raw.append((slot.rid, slot.prompt, slot.chunks,
                                slot.generated, ttft, itl))
         self._free_slot(slot_idx)
@@ -715,6 +710,10 @@ class ContinuousBatchingServer:
         """Convert retired-but-raw requests into FinishedRequests."""
         if not self._done_raw:
             return []
+        if any(isinstance(arr, jax.Array) and not arr.is_ready()
+               for _, _, chunks, *_ in self._done_raw
+               for arr, _, _ in chunks):
+            self.stats.retire_waits += 1
         fetched: dict = {}
         out = []
         for rid, prompt, chunks, generated, ttft, itl in self._done_raw:
@@ -889,7 +888,7 @@ class ContinuousBatchingServer:
         pos_arg = (jnp.int32(self.slots[active[0]].pos) if aligned
                    else jnp.asarray(pos))
         t0 = self._timer()
-        with kops.execution_plan(self.plan):
+        with self._launch(seg), kops.execution_plan(self.plan):
             buf, self._toks, self.cache = seg(
                 self.params, self._toks, self.cache, pos_arg, state)
         # segment dispatch wall (trace + enqueue; execution is async) —
@@ -918,9 +917,13 @@ class ContinuousBatchingServer:
         slots; returns requests that finished this step (synced).
         ``draining=True`` tells segment sizing no live submit can arrive
         (the router's step-wise drain uses it to keep boundaries
-        identical to a blocking ``run()``)."""
-        self._advance(draining=draining)
-        return self._materialize()
+        identical to a blocking ``run()``). Under a profiler session the
+        call is a ``step`` span, the host copy of finished tokens a
+        ``retire`` span inside it (see ``launch.tracing``)."""
+        with self.tracer.span("step"):
+            self._advance(draining=draining)
+            with self.tracer.span("retire"):
+                return self._materialize()
 
     def _has_work(self) -> bool:
         return bool(self.pending) or any(not s.free for s in self.slots)
@@ -1291,9 +1294,13 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
                     f"{self.mgr.alloc.capacity} — raise num_blocks or "
                     "shrink the request"
                 )
-        return super().submit(prompt, max_new_tokens, sample,
-                              priority=priority, ttft_target=ttft_target,
-                              itl_target=itl_target)
+        rid = super().submit(prompt, max_new_tokens, sample,
+                             priority=priority, ttft_target=ttft_target,
+                             itl_target=itl_target)
+        # per request: queued until its first staging span is taken,
+        # then staged until admission (launch.tracing)
+        self.tracer.begin("queued", rid)
+        return rid
 
     # -- retrieval stage (RAG) ---------------------------------------------
     def submit_query(self, query, max_new_tokens: int,
@@ -1443,7 +1450,7 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
         fn = self._compiled(
             ("stage", k, c, self.blocks_per_table, self._plan_key),
             self._stage_fn)
-        with kops.execution_plan(self.plan):
+        with self._launch(fn), kops.execution_plan(self.plan):
             _, self.mgr.pool.cache = fn(
                 self.params, {"tokens": jnp.asarray(toks)},
                 self.mgr.pool.cache, None, jnp.asarray(pos),
@@ -1490,6 +1497,8 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
                 self.stats.stage_stalls += 1
                 break
             self.pending.remove(req)
+            self.tracer.end("queued", req.rid)
+            self.tracer.begin("staged", req.rid)
             meta = self._rag_meta.pop(req.rid, None)
             if meta is not None:
                 # chunk-reuse accounting: of the retrieved-chunk blocks
@@ -1668,6 +1677,7 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
             i = free.pop(0)
             self._staging.remove(st)
             r, sp = st.req, st.resume
+            self.tracer.end("staged", r.rid)
             slot = self.slots[i]
             slot.rid = r.rid
             slot.prompt = r.prompt
@@ -1891,13 +1901,16 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
                 # the I/O worker; collecting it needs no quiesce.)
                 jax.block_until_ready(self._toks)
             self._drain_queries(overlapped=False)
-        self._stage(catch_up=not active_now)
-        self._admit_ready()
+        with self.tracer.span("stage"):
+            self._stage(catch_up=not active_now)
+        with self.tracer.span("admit"):
+            self._admit_ready()
         self._sync_pool_stats()
         if self._spec_on:
             self._advance_spec(draining)
             return
-        active, steps = self._grow_active(draining)
+        with self.tracer.span("grow"):
+            active, steps = self._grow_active(draining)
         if not active:
             return
         # growth may have extended (or preemption rebuilt) block spans —
@@ -1949,7 +1962,7 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
         a_toks = jnp.asarray(np.asarray(admit_toks,
                                         np.int32).reshape(-1, 1))
         t0 = self._timer()
-        with kops.execution_plan(self.plan):
+        with self._launch(seg), kops.execution_plan(self.plan):
             buf, self._toks, self.mgr.pool.cache = seg(
                 self.params, self._toks, self.mgr.pool.cache, pos_arg,
                 bt, a_slots, a_toks, state,
@@ -1976,9 +1989,6 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
                 # first token dispatched this segment (admitted rows'
                 # correction step ran inside it)
                 slot.first_t = now
-                if slot.req is not None:
-                    self.stats.record_ttft(slot.req.priority,
-                                           now - slot.req.submit_t)
             if slot.remaining == 0:
                 self._retire(i)
         # re-sync after the retirements so stats read at a quiescent
@@ -2052,9 +2062,10 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
                     dpos[i] += take
                     if dpos[i] < pos + 1:
                         final = False
-            drafts, self._draft_cache = fn(
-                self._draft_params, jnp.asarray(chunk), jnp.asarray(clen),
-                jnp.asarray(start), self._draft_cache)
+            with self._launch(fn):
+                drafts, self._draft_cache = fn(
+                    self._draft_params, jnp.asarray(chunk),
+                    jnp.asarray(clen), jnp.asarray(start), self._draft_cache)
             if final:
                 break
         for i in active:
@@ -2070,7 +2081,8 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
         span reaches into allocator-owned blocks — a rejected draft
         triggers no allocation and no copy."""
         k = self.spec.k
-        active, _ = self._grow_active(draining, steps_override=1)
+        with self.tracer.span("grow"):
+            active, _ = self._grow_active(draining, steps_override=1)
         if not active:
             return
         # admitted rows' correction token comes from host history here
@@ -2107,7 +2119,7 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
              self._plan_key),
             self._verify_fn)
         t0 = self._timer()
-        with kops.execution_plan(self.plan):
+        with self._launch(vf), kops.execution_plan(self.plan):
             tgt, self.mgr.pool.cache = vf(
                 self.params, jnp.asarray(toks), self.mgr.pool.cache,
                 jnp.asarray(pos), jnp.asarray(bt_np), state)
@@ -2164,9 +2176,6 @@ class PagedContinuousBatchingServer(ContinuousBatchingServer):
             slot.pos += emit
             if slot.first_t is None:
                 slot.first_t = now
-                if slot.req is not None:
-                    self.stats.record_ttft(slot.req.priority,
-                                           now - slot.req.submit_t)
             if slot.remaining == 0:
                 self._retire(i)
         self.stats.wasted_steps += wasted

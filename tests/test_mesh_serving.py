@@ -251,23 +251,13 @@ def test_router_tokens_match_solo_and_stats_roll_up(nemotron):
 
 
 def test_sum_stats_adds_every_counter_field():
-    a = SchedulerStats(compiles=1, hits=2, admitted=3, evictions=4)
-    b = SchedulerStats(compiles=10, hits=20, admitted=30, evictions=40)
-    a.record_ttft(0, 0.1)
-    a.record_ttft(1, 0.2)
-    b.record_ttft(1, 0.3)
-    b.record_itl(0, 0.05)
+    fields = dataclasses.fields(SchedulerStats)
+    a = SchedulerStats(**{f.name: i + 1 for i, f in enumerate(fields)})
+    b = SchedulerStats(**{f.name: 10 * (i + 1)
+                          for i, f in enumerate(fields)})
     s = sum_stats([a, b])
-    for f in dataclasses.fields(SchedulerStats):
-        va, vb = getattr(a, f.name), getattr(b, f.name)
-        if isinstance(va, dict):
-            # latency-sample dicts pool (concatenate) per priority —
-            # fleet tails come from the pooled samples, not a sum
-            merged = {k: va.get(k, []) + vb.get(k, [])
-                      for k in set(va) | set(vb)}
-            assert getattr(s, f.name) == merged
-        else:
-            assert getattr(s, f.name) == va + vb
+    for f in fields:
+        assert getattr(s, f.name) == getattr(a, f.name) + getattr(b, f.name)
 
 
 def test_router_rejects_bad_policy_and_empty_fleet(nemotron):

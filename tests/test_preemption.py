@@ -238,14 +238,13 @@ def test_edf_orders_by_deadline_inside_a_class(served):
     sched.submit(prompts[0], 3)                     # no target: best-effort
     sched.submit(prompts[1], 3, ttft_target=100.0)
     sched.submit(prompts[2], 3, ttft_target=1.0)    # tightest deadline
-    order = []
+    done = []
     while sched._has_work():
-        order.extend(r.rid for r in sched.step(draining=True))
-    assert order == [2, 1, 0]
-    # per-class latency stats were recorded for the one class in play
-    assert len(sched.stats.ttft_s[0]) == 3
-    assert sched.stats.ttft_tail(q=95) >= 0.0
-    assert len(sched.stats.itl_s[0]) == 3
+        done.extend(sched.step(draining=True))
+    assert [r.rid for r in done] == [2, 1, 0]
+    # each finished request carries its own dispatch-time latencies
+    assert all(r.ttft >= 0.0 for r in done)
+    assert all(r.itl >= 0.0 for r in done)
 
 
 def test_scheduling_mode_validated(served):

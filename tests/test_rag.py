@@ -180,11 +180,12 @@ def test_chunk_reuse_across_distinct_queries(served):
     assert st.retrieval_chunk_blocks > 0
     assert st.retrieval_chunk_hits > 0, "no chunk-level reuse"
     assert 0 < st.retrieval_chunk_hit_rate <= 1
-    # block-granular prefix accounting (the satellite fix): denominator
-    # is prompt blocks walked, old lookups-based rate kept deprecated
+    # block-granular prefix accounting: the denominator is prompt
+    # blocks walked, so the rate is hits over walked blocks exactly
     assert st.prefix_prompt_blocks >= st.prefix_block_hits > 0
     assert 0 < st.prefix_hit_rate <= 1
-    assert 0 < st.prefix_lookup_hit_rate <= 1
+    assert st.prefix_hit_rate == (st.prefix_block_hits
+                                  / st.prefix_prompt_blocks)
     assert "retrieval" in st.summary()
 
 
