@@ -417,12 +417,13 @@ def paged_attention_gqa(
     """Paged GQA decode attention, in place on the block pool.
 
     Dispatch mirrors the sidebar MLP ops: the Pallas kernel (table rows
-    in SMEM, per-block DMA, online softmax) when eligible on TPU or
-    under ``interpret``; otherwise the jnp reference — the slab path's
-    dense math fed by a per-layer table gather, bit-identical to it. A
-    layer planned ``FLEXIBLE_DMA`` also takes the gather route (the
-    dense-view round-trip IS that mode's memory discipline), recorded as
-    variant ``"dma"`` so per-layer plan choices stay observable.
+    in SMEM, each row's live blocks DMA'd whole, online softmax) when
+    eligible on TPU or under ``interpret``; otherwise the jnp reference
+    — the slab path's dense math fed by a per-layer table gather,
+    bit-identical to it. A layer planned ``FLEXIBLE_DMA`` also takes
+    the gather route (the dense-view round-trip IS that mode's memory
+    discipline), recorded as variant ``"dma"`` so per-layer plan
+    choices stay observable.
 
     Under tensor-parallel serving this runs INSIDE the shard_map body,
     so ``h``/``hkv`` are per-shard locals (``H/tp``, ``Hkv/tp``) and the

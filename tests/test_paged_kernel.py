@@ -92,6 +92,22 @@ def _check_exact(solo, done, reqs, arch=""):
 # ---------------------------------------------------------------------------
 
 
+def _pools(rng, P, Hkv, bs, Dh, quantized):
+    if quantized:
+        k = jnp.asarray(rng.randint(-127, 128, (P, Hkv, bs, Dh))
+                        .astype(np.int8))
+        v = jnp.asarray(rng.randint(-127, 128, (P, Hkv, bs, Dh))
+                        .astype(np.int8))
+        ks = jnp.asarray((rng.rand(P, Hkv, bs).astype(np.float32) + .5)
+                         / 127)
+        vs = jnp.asarray((rng.rand(P, Hkv, bs).astype(np.float32) + .5)
+                         / 127)
+        return k, v, ks, vs
+    k = jnp.asarray(rng.randn(P, Hkv, bs, Dh).astype(np.float32))
+    v = jnp.asarray(rng.randn(P, Hkv, bs, Dh).astype(np.float32))
+    return k, v, None, None
+
+
 def _pool_problem(seed=0, *, quantized=False):
     """Random pool + tables with duplicate entries, a scratch-padded
     tail row, and ragged lengths (one mid-block, one block-aligned, one
@@ -103,32 +119,98 @@ def _pool_problem(seed=0, *, quantized=False):
     tables[0, 1:] = kvp.SCRATCH_BLOCK          # short row, unused tail
     tables[1, 2] = tables[1, 1]                # duplicate (prefix-share)
     lengths = jnp.asarray(np.array([5, bs * 2, bs * nb], np.int32))
-    if quantized:
-        k = jnp.asarray(rng.randint(-127, 128, (P, Hkv, bs, Dh))
-                        .astype(np.int8))
-        v = jnp.asarray(rng.randint(-127, 128, (P, Hkv, bs, Dh))
-                        .astype(np.int8))
-        ks = jnp.asarray((rng.rand(P, Hkv, bs).astype(np.float32) + .5)
-                         / 127)
-        vs = jnp.asarray((rng.rand(P, Hkv, bs).astype(np.float32) + .5)
-                         / 127)
-    else:
-        k = jnp.asarray(rng.randn(P, Hkv, bs, Dh).astype(np.float32))
-        v = jnp.asarray(rng.randn(P, Hkv, bs, Dh).astype(np.float32))
-        ks = vs = None
+    k, v, ks, vs = _pools(rng, P, Hkv, bs, Dh, quantized)
     return q, k, v, ks, vs, jnp.asarray(tables), lengths, Dh ** -0.5
 
 
-@pytest.mark.parametrize("quantized", [False, True],
-                         ids=["fp32", "int8"])
-def test_gqa_kernel_matches_reference(quantized):
-    q, k, v, ks, vs, tables, lengths, scale = _pool_problem(
-        seed=1, quantized=quantized)
+# The shapes the kernel's walk has to handle: (KV heads, group, block
+# size, table width, blocks a chunk holds for fp32 pools, row lengths).
+# int8 pools round a chunk up to whole 128-lane spans of scales, so
+# there the first cases walk the table as one chunk.
+_WALKS = {
+    # Nemotron's grouping; lengths of 1, on a block boundary, on a chunk
+    # boundary, mid-chunk and the whole table
+    "group6": (8, 6, 8, 6, 2, [1, 8, 16, 21, 48]),
+    # DeepSeek's: one query head per KV head; a table width (7) that is
+    # not a multiple of the chunk (3), so the last chunk is short
+    "group1": (16, 1, 8, 7, 3, [1, 24, 56, 13]),
+    # rows far shorter than the table they are handed
+    "short-rows": (2, 4, 8, 32, 4, [3, 256, 9]),
+    # several chunks of 128 positions for int8 pools too
+    "chunks": (2, 2, 16, 20, 8, [1, 128, 200, 320, 16]),
+}
+
+
+def _walk_problem(seed, hkv, group, bs, nb, lengths, *, quantized, dh=16):
+    rng = np.random.RandomState(seed)
+    b = len(lengths)
+    P = b * nb + 1
+    q = jnp.asarray(rng.randn(b, hkv * group, dh).astype(np.float32))
+    # every row its own scattered blocks; block 0 is the scratch block
+    tables = (rng.permutation(P - 1)[:b * nb] + 1).reshape(b, nb)
+    k, v, ks, vs = _pools(rng, P, hkv, bs, dh, quantized)
+    return (q, k, v, ks, vs, jnp.asarray(tables.astype(np.int32)),
+            jnp.asarray(np.array(lengths, np.int32)), dh ** -0.5)
+
+
+def _walk_pages(monkeypatch, case, quantized):
+    """Size the kernel's chunk to the case's ``pages`` blocks of its
+    pools (int8 rounds that up, see ``_WALKS``)."""
+    hkv, _, bs, nb, pages, _ = _WALKS[case]
+    block = hkv * bs * 16 * (1 if quantized else 4)
+    monkeypatch.setattr(pa, "_CHUNK_BYTES", 2 * block * pages)
+    if not quantized:
+        assert pa._pages_per_chunk(block, bs, nb, quantized) == pages
+
+
+@pytest.mark.parametrize("quantized, case", [
+    pytest.param(quantized, case, id=kind + ("" if case is None
+                                             else f"-{case}"))
+    for case in (None, *_WALKS)
+    for kind, quantized in (("fp32", False), ("int8", True))
+])
+def test_gqa_kernel_matches_reference(quantized, case, monkeypatch):
+    if case is None:
+        q, k, v, ks, vs, tables, lengths, scale = _pool_problem(
+            seed=1, quantized=quantized)
+    else:
+        hkv, group, bs, nb, _, lengths = _WALKS[case]
+        _walk_pages(monkeypatch, case, quantized)
+        q, k, v, ks, vs, tables, lengths, scale = _walk_problem(
+            1, hkv, group, bs, nb, lengths, quantized=quantized)
     ref = pa.paged_gqa_reference(q, k, v, tables, lengths, scale=scale,
                                  k_scale=ks, v_scale=vs)
     out = pa.paged_gqa_kernel(q, k, v, tables, lengths, scale=scale,
                               k_scale=ks, v_scale=vs, interpret=True)
     assert out.dtype == q.dtype and out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["fp32", "int8"])
+def test_gqa_kernel_never_reads_past_the_frontier(quantized, monkeypatch):
+    """Table entries past a row's frontier name blocks full of NaN (int8:
+    NaN scales); the kernel's output stays finite and equal to the
+    reference over the same table with those blocks clean."""
+    case = "group1"
+    hkv, group, bs, nb, _, lengths = _WALKS[case]
+    _walk_pages(monkeypatch, case, quantized)
+    q, k, v, ks, vs, tables, lengths, scale = _walk_problem(
+        2, hkv, group, bs, nb, lengths, quantized=quantized)
+    t = np.asarray(tables)
+    live = -(-np.asarray(lengths) // bs)
+    dead = np.concatenate([t[r, n:] for r, n in enumerate(live)])
+    assert dead.size                           # the case has a tail
+    ref = pa.paged_gqa_reference(q, k, v, tables, lengths, scale=scale,
+                                 k_scale=ks, v_scale=vs)
+    if quantized:
+        ks, vs = (x.at[dead].set(jnp.nan) for x in (ks, vs))
+    else:
+        k, v = (x.at[dead].set(jnp.nan) for x in (k, v))
+    out = pa.paged_gqa_kernel(q, k, v, tables, lengths, scale=scale,
+                              k_scale=ks, v_scale=vs, interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 

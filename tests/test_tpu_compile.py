@@ -81,9 +81,20 @@ def test_sidebar_gated_mlp_compiles(one_chip, rows):
              ((f, d), BF16))
 
 
-@pytest.mark.parametrize("kv", [BF16, jnp.int8], ids=["bf16", "int8"])
-def test_paged_gqa_kernel_compiles(one_chip, kv):
-    b, h, hkv, bs, dh, pool, nb = 8, 48, 8, 16, 128, 1024, 100
+_GQA_SHAPES = {                            # b, h, hkv, pool, nb
+    "": (8, 48, 8, 1024, 100),
+    "-nemotron8": (32, 48, 8, 11405, 128),  # the Nemotron-4-15B decode cell
+    "-deepseek8": (16, 32, 32, 4420, 256),  # the DeepSeek-7B sessions cell
+}
+
+
+@pytest.mark.parametrize("kv, b, h, hkv, pool, nb", [
+    pytest.param(kv, *shape, id=name + where)
+    for where, shape in _GQA_SHAPES.items()
+    for name, kv in (("bf16", BF16), ("int8", jnp.int8))
+])
+def test_paged_gqa_kernel_compiles(one_chip, kv, b, h, hkv, pool, nb):
+    bs, dh = 16, 128
     shapes = [((b, h, dh), BF16), ((pool, hkv, bs, dh), kv),
               ((pool, hkv, bs, dh), kv), ((b, nb), I32), ((b,), I32)]
     if kv == jnp.int8:
