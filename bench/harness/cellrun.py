@@ -116,9 +116,24 @@ def peak_bytes(devs) -> int:
     return int(max(d.memory_stats()["peak_bytes_in_use"] for d in devs))
 
 
-def build(cell: manifest.Cell, seed: int, dev, log):
+def device_bytes(tree, devs) -> list[int]:
+    """Bytes of ``tree``'s arrays held on each of ``devs``."""
+    import jax
+
+    held = dict.fromkeys(devs, 0)
+    for x in jax.tree.leaves(tree):
+        for shard in x.addressable_shards:
+            held[shard.device] += shard.data.nbytes
+    return [held[d] for d in devs]
+
+
+def build(cell: manifest.Cell, seed: int, devs: list, log):
     """Weights from the seed, and the server over a pool that fills what
-    the weights leave of the chip, less the headroom."""
+    the weights leave of the fullest chip, less the headroom; a block
+    costs what the program's own cache specs say one chip holds of it.
+    On more than one chip the server runs the program's tensor-parallel
+    path over a (1, chips) mesh, each weight drawn straight into the
+    program's own placement."""
     import jax
 
     from repro.launch.scheduler import PagedContinuousBatchingServer
@@ -128,24 +143,53 @@ def build(cell: manifest.Cell, seed: int, dev, log):
     st = Server.of(cell.traffic)
     pcfg = model.program_config(cell.config)
     api = get_model(pcfg)
+    # ``local``: the configuration as one chip holds it (its share of
+    # the heads), whose cache specs size a pool block on each chip
+    mesh, minfo, shardings, local = None, L.HOST, None, pcfg
+    if len(devs) > 1:
+        from repro.launch.mesh import make_serving_mesh
+        from repro.launch.serve import make_tp_spec
+
+        mesh = make_serving_mesh((1, len(devs)))
+        if set(mesh.devices.flat) != set(devs):
+            raise NoChip(f"the mesh's devices {mesh.devices.flat} are not "
+                         f"the cell's {devs}")
+        tp = make_tp_spec(pcfg, api, mesh)
+        minfo, shardings = tp.minfo, tp.param_shardings()
+        local = tp.cfg_local
     t = time.perf_counter()
-    params = model.program_params(cell.config, api, pcfg, seed, L.HOST)
+    params = model.program_params(cell.config, api, pcfg, seed, minfo,
+                                  shardings)
     jax.block_until_ready(params)
-    wbytes = sum(x.nbytes for x in jax.tree.leaves(params))
-    log(f"weights_s {time.perf_counter() - t} weight_bytes {wbytes}")
-    item = np.dtype(pcfg.kv_cache_dtype).itemsize
-    per_block = (2 * pcfg.num_layers * pcfg.num_kv_heads * pcfg.head_dim
-                 * item * st.block_size)
-    cap = capacity(dev)
+    held = device_bytes(params, devs)
+    wbytes = max(held)
+    log(f"weights_s {time.perf_counter() - t} weight_bytes "
+        f"{sum(x.nbytes for x in jax.tree.leaves(params))} per_device "
+        f"{held}")
+    per_block = L.param_bytes(api.cache_specs(local, L.HOST, 1,
+                                              st.block_size))
+    cap = min(capacity(d) for d in devs)
     blocks = int((cap - wbytes - st.headroom_bytes) // per_block)
-    log(f"pool bytes_limit {cap} blocks {blocks} bytes {blocks * per_block} "
-        f"tokens {blocks * st.block_size} max_len {st.max_len}")
+    log(f"pool bytes_limit {cap} blocks {blocks} bytes_per_device "
+        f"{blocks * per_block} tokens {blocks * st.block_size} "
+        f"max_len {st.max_len}")
     srv = PagedContinuousBatchingServer(
-        pcfg, params, num_slots=st.slots, max_len=st.max_len,
+        pcfg, params, mesh=mesh, num_slots=st.slots, max_len=st.max_len,
         block_size=st.block_size, num_blocks=blocks,
         prefill_chunk=st.prefill_chunk, segment=st.segment,
         stage_ahead=st.stage_ahead, kernel="paged")
     return srv, st
+
+
+def reference_weights(cell: manifest.Cell, seed: int, devs: list) -> dict:
+    """The plain reference's weights: the same draw, placed by the
+    benchmark's own rule over the cell's chips (each leaf's last
+    dimension split), so that no leaf is whole on one chip."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(devs), ("chips",))
+    return weights.make(cell.config, seed, weights.spread(
+        weights.layout(cell.config), mesh, "chips"))
 
 
 def warm_up(srv, mix: dict, *, vocab: int, seed: int,
@@ -220,7 +264,7 @@ def run(cell: manifest.Cell, *, seed: int, seconds: float, trace: bool,
     pk = peaks.lookup(dev.device_kind)
     log(f"compile_cache {compile_cache(root)}")
     clock = CompileClock()
-    srv, st = build(cell, seed, dev, log)
+    srv, st = build(cell, seed, devs, log)
     mix = cell.traffic
     vocab = cell.config["vocab_size"]
 
@@ -321,7 +365,7 @@ def run(cell: manifest.Cell, *, seed: int, seconds: float, trace: bool,
     gc.collect()
     t = time.perf_counter()
     ref = manifest.reference(cell.config["reference"])
-    w = weights.make(cell.config, seed)
+    w = reference_weights(cell, seed, devs)
     pad = -(-st.max_len // 128) * 128
     kw = dict(pad_to=pad, positions=traffic.max_answer(mix))
     # the control puts the float8 reference's picks in place of the

@@ -2,10 +2,16 @@
 the weights the benchmark makes from the seed.
 
 The weights are the benchmark's own: every leaf is drawn from the seed
-by ``weights.make`` under a name of the benchmark's layout, and the
-same call gives the plain reference the same numbers. The program gets
-them re-nested into its own parameter tree, after a check that its tree
-has exactly these leaves, shapes and dtypes.
+by ``weights.make`` under a name of the layout that the configuration's
+reference gives, and the same call gives the plain reference the same
+numbers. The program gets them re-nested into its own parameter tree,
+after a check that its tree has exactly these leaves, shapes and
+dtypes.
+
+A configuration's sizes map onto the program's ``ModelConfig`` through
+``FIELDS``; an optional ``"program"`` object sets further fields by
+their own names (an architecture's own sizes, say), each held to the
+registry's published value unless its key is listed in ``reduced``.
 """
 
 from __future__ import annotations
@@ -61,9 +67,19 @@ def program_config(cfg: dict):
         use_pallas=True,
     )
     cut = set(cfg.get("reduced", {}))
-    for key, field in FIELDS.items():
-        if key not in cut and getattr(base, field) != kw[field] and \
-                key not in ("rope_theta", "rms_norm_eps"):
+    checked = {key: field for key, field in FIELDS.items()
+               if key not in ("rope_theta", "rms_norm_eps")}
+    extra = {field: tuple(v) if isinstance(v, list) else v
+             for field, v in cfg.get("program", {}).items()}
+    known = {f.name for f in dataclasses.fields(base)}
+    for field, value in extra.items():
+        if field not in known:
+            raise ValueError(f"{cfg['name']}: 'program' sets {field!r}, "
+                             "which the program's ModelConfig lacks")
+        checked[field] = field
+        kw[field] = value
+    for key, field in checked.items():
+        if key not in cut and getattr(base, field) != kw[field]:
             raise ValueError(
                 f"{cfg['name']}: {key} {kw[field]} differs from the "
                 f"program's {cfg['program_arch']} ({getattr(base, field)})"
@@ -75,9 +91,11 @@ def _path_name(path) -> str:
     return "/".join(str(getattr(k, "key", k)) for k in path)
 
 
-def program_params(cfg: dict, api, pcfg, seed: int, minfo):
+def program_params(cfg: dict, api, pcfg, seed: int, minfo, shardings=None):
     """The benchmark's weights for ``seed``, nested as the program's
-    parameter tree. The leaves must match the program's specs exactly."""
+    parameter tree. The leaves must match the program's specs exactly.
+    ``shardings``, a tree like the parameters' (the program's own
+    tensor-parallel placement), places each leaf as it is drawn."""
     specs = api.param_specs(pcfg, minfo)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         specs, is_leaf=lambda x: hasattr(x, "pspec"))
@@ -89,6 +107,9 @@ def program_params(cfg: dict, api, pcfg, seed: int, minfo):
         diff = sorted(set(want.items()) ^ set(have.items()))
         raise ValueError(f"the program's parameters differ from the "
                          f"benchmark's layout: {diff[:6]}")
-    made = weights.make(cfg, seed)
+    if shardings is not None:
+        shardings = {_path_name(p): s for p, s in
+                     jax.tree_util.tree_flatten_with_path(shardings)[0]}
+    made = weights.make(cfg, seed, shardings)
     return jax.tree_util.tree_unflatten(
         treedef, [made[_path_name(p)] for p, _ in flat])

@@ -17,7 +17,12 @@ Steadiness: every seed gets the same work in another order. Lengths,
 gaps and think times are drawn in blocks of ``block`` items from fixed
 quantiles of their distributions, and the seed only permutes each
 block (and picks token ids). So any window that spans whole blocks
-offers the same tokens on every seed.
+offers the same tokens on every seed. A window that cuts sessions
+short holds tokens that depend on the order too (a five-turn session
+that opens early against one that opens late), so a sessions mix with
+``"fixed_schedule": true`` takes every permutation from a stream that
+all seeds share: each seed then sends the same turns at the same times,
+and picks only their token ids.
 """
 
 from __future__ import annotations
@@ -30,11 +35,20 @@ from typing import Any
 import numpy as np
 
 SEED_MASK = 2**63 - 1
+SHARED = SEED_MASK + 1      # the seed of a fixed schedule: no --seed's
 
 
 def rng_for(seed: int, stream: int) -> np.random.Generator:
     """Independent stream ``stream`` of ``seed`` (any non-negative int)."""
     return np.random.default_rng([int(seed) & SEED_MASK, int(stream)])
+
+
+def plan_rng(spec: dict, rng: np.random.Generator,
+             stream: int) -> np.random.Generator:
+    """Where the mix's permutations come from: the seed's own ``rng``,
+    or with ``fixed_schedule`` one stream that every seed shares."""
+    return (np.random.default_rng([SHARED, int(stream)])
+            if spec.get("fixed_schedule") else rng)
 
 
 def quantiles(dist: dict, n: int) -> np.ndarray:
@@ -153,16 +167,17 @@ class Sessions:
         # as in ``Poisson``: a block of session arrivals spans exactly
         # block / rate seconds
         self.span, self.block = n / rate, n
+        plan = plan_rng(spec, self.rng, stream)
         gaps = quantiles(gap, n)
-        self.gaps = _Blocks(gaps * (self.span / gaps.sum()), self.rng)
+        self.gaps = _Blocks(gaps * (self.span / gaps.sum()), plan)
         lo, hi = spec["turns"]["min"], spec["turns"]["max"]
-        self.turns = _Blocks(np.resize(np.arange(lo, hi + 1), n), self.rng)
-        self.users = _Blocks(_int_quantiles(spec["user_tokens"], n), self.rng)
+        self.turns = _Blocks(np.resize(np.arange(lo, hi + 1), n), plan)
+        self.users = _Blocks(_int_quantiles(spec["user_tokens"], n), plan)
         self.answers = _Blocks(_int_quantiles(spec["answer_tokens"], n),
-                               self.rng)
-        self.thinks = _Blocks(quantiles(spec["think_s"], n), self.rng)
+                               plan)
+        self.thinks = _Blocks(quantiles(spec["think_s"], n), plan)
         self.systems = _Blocks(np.resize(np.arange(spec["system_prompts"]),
-                                         n), self.rng)
+                                         n), plan)
         # the shared system prompts: the same ids on every seed, so the
         # prefix cache sees the same sharing whatever the seed
         sys_rng = np.random.default_rng(1)
@@ -176,11 +191,19 @@ class Sessions:
         self._seq = 0
 
     def _new_session(self) -> None:
+        """Open the next session with its whole plan: every turn's user
+        tokens, answer length and the pause before it, drawn now, so
+        that the plan does not hang on the order in which answers come
+        back."""
         sid = self.n_sessions
         self.n_sessions += 1
-        s = {"turns": int(self.turns.take()),
+        n = int(self.turns.take())
+        s = {"turns": n, "turn": 0,
              "history": self.system_tokens[int(self.systems.take())].copy(),
-             "turn": 0}
+             "users": [self.rng.integers(0, self.vocab, int(self.users.take()),
+                                         dtype=np.int32) for _ in range(n)],
+             "answers": [int(self.answers.take()) for _ in range(n)],
+             "thinks": [float(self.thinks.take()) for _ in range(n - 1)]}
         self.sessions[sid] = s
         self._push(self._turn(sid, self.t_next_session))
         self.t_next_session += float(self.gaps.take())
@@ -189,12 +212,10 @@ class Sessions:
 
     def _turn(self, sid: int, due: float) -> Request:
         s = self.sessions[sid]
-        user = self.rng.integers(0, self.vocab, int(self.users.take()),
-                                 dtype=np.int32)
-        s["history"] = np.concatenate([s["history"], user])
+        s["history"] = np.concatenate([s["history"], s["users"][s["turn"]]])
         return Request(key=(sid, s["turn"]), due=due,
                        prompt=s["history"].copy(),
-                       max_new=int(self.answers.take()))
+                       max_new=s["answers"][s["turn"]])
 
     def _push(self, req: Request) -> None:
         heapq.heappush(self.ready, (req.due, self._seq, req))
@@ -220,7 +241,7 @@ class Sessions:
         if s["turn"] >= s["turns"]:
             del self.sessions[sid]
             return
-        self._push(self._turn(sid, t + float(self.thinks.take())))
+        self._push(self._turn(sid, t + s["thinks"][turn]))
 
     @staticmethod
     def max_len(spec: dict) -> int:

@@ -1,56 +1,27 @@
 """Weights made from the seed, on the device, in one jitted call.
 
-``layout`` names every leaf of a decoder configuration (pre-norm blocks
-with grouped-query attention and a plain or gated MLP, tied input and
-output embeddings) with its shape, dtype and scale. ``make`` draws them
-all from the seed in the served dtype. The program and the plain
-reference both take their weights from here, so neither takes anything
-the other made.
+A configuration names its plain reference (``"reference"``), and that
+module's ``layout(cfg)`` names every leaf with its shape, dtype, kind
+and scale. ``make`` draws them all from the seed in the served dtype,
+each leaf straight into its sharding where one is given: the values do
+not depend on it (``jax_threefry_partitionable``). The program and the
+plain reference both take their weights from here, so neither takes
+anything the other made.
 """
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
-DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-# logits of about this spread, whatever the hidden size: the tied
-# embedding's entries are drawn at LOGIT_STD / sqrt(hidden)
-LOGIT_STD = 2.0
-# norm gains are drawn about 1 so that a gain the program dropped or
-# applied twice shows in the comparison
-NORM_STD = 0.1
+from harness import manifest
 
 
-def layout(cfg: dict) -> dict[str, tuple[tuple[int, ...], object, str, float]]:
-    """name -> (shape, dtype, kind, scale). ``kind`` is ``normal``
-    (zero mean) or ``gain`` (mean 1)."""
-    dt = DTYPES[cfg["torch_dtype"]]
-    n, d = cfg["num_hidden_layers"], cfg["hidden_size"]
-    h, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh, f, v = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
-    v = -(-v // 16) * 16
-
-    def mat(shape):
-        return ((n, *shape), dt, "normal", 1.0 / math.sqrt(shape[0]))
-
-    out = {
-        "embed": ((v, d), dt, "normal", LOGIT_STD / math.sqrt(d)),
-        "final_norm": ((d,), dt, "gain", NORM_STD),
-        "blocks/dense/attn_norm": ((n, d), dt, "gain", NORM_STD),
-        "blocks/dense/mlp_norm": ((n, d), dt, "gain", NORM_STD),
-        "blocks/dense/attn/wq": mat((d, h * dh)),
-        "blocks/dense/attn/wk": mat((d, hkv * dh)),
-        "blocks/dense/attn/wv": mat((d, hkv * dh)),
-        "blocks/dense/attn/wo": mat((h * dh, d)),
-        "blocks/dense/mlp/w_up": mat((d, f)),
-        "blocks/dense/mlp/w_down": mat((f, d)),
-    }
-    if cfg["gated_mlp"]:
-        out["blocks/dense/mlp/w_gate"] = mat((d, f))
-    return out
+def layout(cfg: dict) -> dict:
+    """name -> (shape, dtype, kind, scale), from the configuration's
+    reference. ``kind`` is ``normal`` (zero mean) or ``gain`` (mean 1)."""
+    return manifest.reference(cfg["reference"]).layout(cfg)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -60,9 +31,27 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def make(cfg: dict, seed: int) -> dict[str, jax.Array]:
+def spread(lay: dict, mesh, axis: str) -> dict:
+    """The benchmark's own placement of every leaf over ``mesh``: its
+    last dimension split over ``axis`` where the axis's size divides
+    it, else whole on every device."""
+    n = mesh.shape[axis]
+
+    def spec(shape):
+        if not shape or shape[-1] % n:
+            return PartitionSpec()
+        return PartitionSpec(*([None] * (len(shape) - 1)), axis)
+
+    return {name: NamedSharding(mesh, spec(shape))
+            for name, (shape, *_) in lay.items()}
+
+
+def make(cfg: dict, seed: int,
+         shardings: dict | None = None) -> dict[str, jax.Array]:
     """Every leaf of ``layout(cfg)`` for ``seed``; leaf ``i`` of the
-    sorted layout is drawn from ``fold_in(key, i)``."""
+    sorted layout is drawn from ``fold_in(key, i)``. ``shardings`` (name
+    -> sharding) places each leaf as it is drawn, so that no leaf is
+    ever whole on one device."""
     lay = layout(cfg)
 
     def draw(key):
@@ -74,4 +63,6 @@ def make(cfg: dict, seed: int) -> dict[str, jax.Array]:
             out[name] = (x + 1.0 if kind == "gain" else x).astype(dt)
         return out
 
-    return jax.jit(draw)(seed_key(seed))
+    if shardings is None:
+        return jax.jit(draw)(seed_key(seed))
+    return jax.jit(draw, out_shardings=shardings)(seed_key(seed))

@@ -13,16 +13,60 @@ a final RMSNorm and logits against the tied embedding.
 rounded to float8 (e4m3) with a scale per row or column along the
 contraction: the step below the configuration's bfloat16. It decides
 nothing in a run; it is what the comparison has to be able to fail.
+
+``layout`` names every leaf of the weights this reference reads, with
+its shape, dtype and scale; the harness draws them from the seed
+(``harness.weights``) for the program and for this reference alike.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 F8_MAX = 448.0   # largest finite float8_e4m3fn
+DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+# logits of about this spread, whatever the hidden size: the tied
+# embedding's entries are drawn at LOGIT_STD / sqrt(hidden)
+LOGIT_STD = 2.0
+# norm gains are drawn about 1 so that a gain the program dropped or
+# applied twice shows in the comparison
+NORM_STD = 0.1
+
+
+def layout(cfg: dict) -> dict[str, tuple[tuple[int, ...], object, str, float]]:
+    """name -> (shape, dtype, kind, scale) of every leaf: pre-norm blocks
+    with grouped-query attention and a plain or gated MLP, stacked over
+    the layers, and tied input and output embeddings (the vocabulary
+    padded to a multiple of 16). ``kind`` is ``normal`` (zero mean) or
+    ``gain`` (mean 1)."""
+    dt = DTYPES[cfg["torch_dtype"]]
+    n, d = cfg["num_hidden_layers"], cfg["hidden_size"]
+    h, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    dh, f, v = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
+    v = -(-v // 16) * 16
+
+    def mat(shape):
+        return ((n, *shape), dt, "normal", 1.0 / math.sqrt(shape[0]))
+
+    out = {
+        "embed": ((v, d), dt, "normal", LOGIT_STD / math.sqrt(d)),
+        "final_norm": ((d,), dt, "gain", NORM_STD),
+        "blocks/dense/attn_norm": ((n, d), dt, "gain", NORM_STD),
+        "blocks/dense/mlp_norm": ((n, d), dt, "gain", NORM_STD),
+        "blocks/dense/attn/wq": mat((d, h * dh)),
+        "blocks/dense/attn/wk": mat((d, hkv * dh)),
+        "blocks/dense/attn/wv": mat((d, hkv * dh)),
+        "blocks/dense/attn/wo": mat((h * dh, d)),
+        "blocks/dense/mlp/w_up": mat((d, f)),
+        "blocks/dense/mlp/w_down": mat((f, d)),
+    }
+    if cfg["gated_mlp"]:
+        out["blocks/dense/mlp/w_gate"] = mat((d, f))
+    return out
 
 
 def _f8(x: jax.Array, axis: int) -> jax.Array:

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from harness import manifest
+from harness import manifest, model
 
 BENCH = Path(__file__).resolve().parent
 
@@ -22,6 +22,8 @@ def test_every_cell_of_the_manifest_resolves():
             assert callable(manifest.reader(m["name"]))
         assert manifest.reference(cell.config["reference"]).logits
         assert cell.config["name"] == w["config"]
+        # every size not in ``reduced`` is the registry's published one
+        model.program_config(cell.config)
 
 
 def test_a_new_config_mix_metric_and_cell_from_files_alone(tmp_path):

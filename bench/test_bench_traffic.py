@@ -110,6 +110,49 @@ def test_sessions_same_seed_same_schedule():
     assert run(5) != run(6)
 
 
+def sessions_plan(spec, seed, n=40, answer_delay=lambda key: 1.0):
+    """(key, due, prompt size, answer length) of the first ``n`` turns,
+    and their prompts, each answer back ``answer_delay(key)`` after its
+    turn was due."""
+    gen = traffic.make(spec, vocab=1000, seed=seed, stream=0)
+    plan, prompts = [], []
+    for _ in range(n):
+        req = gen.pop()
+        plan.append((req.key, round(req.due, 9), req.prompt.size,
+                     req.max_new))
+        prompts.append(req.prompt)
+        gen.finished(req.key, np.ones(req.max_new, np.int32),
+                     req.due + answer_delay(req.key))
+    return plan, prompts
+
+
+def test_sessions_fixed_schedule_same_turns_on_every_seed():
+    fixed = {**SESSIONS, "fixed_schedule": True}
+    a, ids_a = sessions_plan(fixed, 5)
+    b, ids_b = sessions_plan(fixed, BIG_SEED)
+    assert a == b
+    assert not all(np.array_equal(x, y) for x, y in zip(ids_a, ids_b))
+    # without it the seed orders the sessions
+    assert sessions_plan(SESSIONS, 5)[0] != sessions_plan(SESSIONS, BIG_SEED)[0]
+
+
+def test_sessions_plan_does_not_hang_on_the_order_answers_return():
+    """A session's turns are drawn when it opens: answers that come back
+    in another order move the later turns' times, not their sizes."""
+    fixed = {**SESSIONS, "fixed_schedule": True}
+    slow_odd = lambda key: 3.0 if key[0] % 2 else 0.2   # noqa: E731
+    a, ids_a = sessions_plan(fixed, 9)
+    b, ids_b = sessions_plan(fixed, 9, answer_delay=slow_odd)
+    sizes = lambda plan: sorted((k, n, m) for k, _, n, m in plan)  # noqa: E731
+    common = {k for k, *_ in a} & {k for k, *_ in b}
+    assert len(common) > 20
+    assert ([x for x in sizes(a) if x[0] in common]
+            == [x for x in sizes(b) if x[0] in common])
+    by_key = lambda plan, ids: {p[0]: i for p, i in zip(plan, ids)}  # noqa: E731
+    pa, pb = by_key(a, ids_a), by_key(b, ids_b)
+    assert all(np.array_equal(pa[k], pb[k]) for k in common)
+
+
 def test_max_len_bounds_every_request():
     assert traffic.max_len(CHAT) == 1536
     assert traffic.max_len(SESSIONS) == 64 + 5 * (40 + 12)
